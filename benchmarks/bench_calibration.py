@@ -102,7 +102,7 @@ def build_report(backend: str = "fake") -> CalibrationReport:
     seed_model = InstanceCostModel(cfg=cfg, hw=TPU_V5E_SIM)
     econf = EngineConfig(max_batch=4, max_seq_len=160, eos_token=-1)
     server = PaDGServer(cfg, n_instances=1, slo=SLO(ttft=60.0, tpot=10.0),
-                        econf=econf, backend="real")
+                        econf=econf, backend="real", cost_model=seed_model)
     records = normalize_rate(load_fixture("azure"), 50.0)[:10]
     reqs = requests_from_trace(records, max_prompt=48, max_output=6,
                                vocab_size=cfg.vocab_size, seed=0)

@@ -1,11 +1,13 @@
 """End-to-end driver: serve a small model with batched requests through
 the full EcoServe stack (real JAX execution, wall-clock scheduling).
 
-Two PaDG instances serve a Poisson request trace; Algorithm 1 routes
-stickily, Algorithm 2 checks constraints, instances alternate
-prefill/decode slots (temporal disaggregation).
+Two PaDG instances, one per device, serve a Poisson request trace;
+Algorithm 1 routes stickily, Algorithm 2 checks constraints, instances
+alternate prefill/decode slots (temporal disaggregation).  On the CPU,
+ask XLA for two host devices:
 
-    PYTHONPATH=src python examples/serve_padg.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=2 \
+        JAX_PLATFORMS=cpu PYTHONPATH=src python examples/serve_padg.py
 """
 import dataclasses
 
@@ -19,6 +21,7 @@ def main():
     from repro.data.pipeline import ByteTokenizer
     from repro.serving.engine import EngineConfig
     from repro.serving.padg_server import PaDGServer
+    from repro.simulator.cost_model import TPU_V5E_SIM, InstanceCostModel
 
     cfg = get_smoke_config("llama3-8b")
     cfg = dataclasses.replace(cfg, num_layers=2, d_model=128, num_heads=2,
@@ -26,9 +29,11 @@ def main():
                               vocab_size=300)
     tok = ByteTokenizer(cfg.vocab_size)
     slo = SLO(ttft=30.0, tpot=5.0)       # loose: CPU wall-clock
+    # the CPU has no seed profile of its own: schedule with the v5e one
     server = PaDGServer(cfg, n_instances=2, slo=slo,
                         econf=EngineConfig(max_batch=4, max_seq_len=64,
-                                           eos_token=-1))
+                                           eos_token=-1),
+                        cost_model=InstanceCostModel(cfg=cfg, hw=TPU_V5E_SIM))
 
     prompts = [
         "the quick brown fox", "ecoserve rolls activation",

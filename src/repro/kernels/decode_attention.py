@@ -22,6 +22,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, block_s: int):
+    b = pl.program_id(0)
     si = pl.program_id(2)
     ns = pl.num_programs(2)
 
@@ -35,7 +36,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     k = k_ref[0, 0]                  # (block_s, D)
     v = v_ref[0, 0]
     d = q.shape[-1]
-    valid_len = len_ref[0, 0]
+    valid_len = len_ref[b]           # scalar-prefetched into SMEM
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -85,29 +86,34 @@ def decode_attention(
     qg = q.reshape(B, Hkv, G, D)
     kg = jnp.moveaxis(k_cache, 2, 1)      # (B, Hkv, Sp, D)
     vg = jnp.moveaxis(v_cache, 2, 1)
-    len2 = lengths.astype(jnp.int32).reshape(B, 1)
 
     kwargs = {}
     if not interpret:
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
+    # index maps receive the scalar-prefetched lengths as a trailing arg
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_s=block_s),
-        grid=(B, Hkv, ns),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, si: (b, 0)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, si: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_s, D), lambda b, h, si: (b, h, si, 0)),
-            pl.BlockSpec((1, 1, block_s, D), lambda b, h, si: (b, h, si, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, si: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Hkv, ns),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, D), lambda b, h, si, _: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_s, D),
+                             lambda b, h, si, _: (b, h, si, 0)),
+                pl.BlockSpec((1, 1, block_s, D),
+                             lambda b, h, si, _: (b, h, si, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, G, D),
+                                   lambda b, h, si, _: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, 1), jnp.float32),
+                pltpu.VMEM((G, D), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, D), jnp.float32),
-        ],
         interpret=interpret,
         **kwargs,
-    )(len2, qg, kg, vg)
+    )(lengths.astype(jnp.int32), qg, kg, vg)
     return out.reshape(B, Hq, D)

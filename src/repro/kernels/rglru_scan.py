@@ -26,22 +26,17 @@ def _rglru_kernel(la_ref, b_ref, h0_ref, o_ref, h_ref, *, block_t: int):
 
     @pl.when(ti == 0)
     def _init():
-        h_ref[0, :] = h0_ref[0, :].astype(jnp.float32)
+        h_ref[...] = h0_ref[0].astype(jnp.float32)
 
-    la = la_ref[0, ...]       # (block_t, block_d) f32
-    b = b_ref[0, ...]
+    # sequential in-VMEM loop over the time block, one (1, block_d) row
+    # of VPU elementwise work per step; h stays in vregs
+    def step(t, h):
+        row = pl.ds(t, 1)
+        h = jnp.exp(la_ref[0, row, :]) * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h.astype(o_ref.dtype)
+        return h
 
-    # log-depth in-VMEM scan over the time block (VPU elementwise ops):
-    # (la1,b1) o (la2,b2) = (la1+la2, b1*exp(la2)+b2)
-    def op(l, r):
-        (la1, b1), (la2, b2) = l, r
-        return la1 + la2, b1 * jnp.exp(la2) + b2
-
-    cum_la, acc_b = jax.lax.associative_scan(op, (la, b), axis=0)
-    h_in = h_ref[0, :]
-    h_all = jnp.exp(cum_la) * h_in[None, :] + acc_b
-    o_ref[0, ...] = h_all.astype(o_ref.dtype)
-    h_ref[0, :] = h_all[-1]
+    h_ref[...] = jax.lax.fori_loop(0, block_t, step, h_ref[...])
 
 
 def rglru_scan(
@@ -81,7 +76,9 @@ def rglru_scan(
                          lambda bi, di, ti: (bi, ti, di)),
             pl.BlockSpec((1, block_t, block_d),
                          lambda bi, di, ti: (bi, ti, di)),
-            pl.BlockSpec((1, block_d), lambda bi, di, ti: (bi, di)),
+            # h0 as (B, 1, d): the block's last two dims then equal the
+            # array's or are lane-aligned, as Mosaic requires
+            pl.BlockSpec((1, 1, block_d), lambda bi, di, ti: (bi, 0, di)),
         ],
         out_specs=pl.BlockSpec((1, block_t, block_d),
                                lambda bi, di, ti: (bi, ti, di)),
@@ -89,5 +86,5 @@ def rglru_scan(
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(log_a, b, h0)
+    )(log_a, b, h0[:, None, :])
     return out[:, :T, :d]

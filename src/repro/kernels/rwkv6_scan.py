@@ -37,7 +37,14 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     u = u_ref[0]           # (1, D) bonus
     S = s_ref[...]         # (D, D)
 
-    cum = jnp.cumsum(lw, axis=0)              # inclusive
+    # inclusive cumsum over time as a lower-triangular matmul (Mosaic has
+    # no cumsum); HIGHEST keeps the f32 log decays exact enough
+    idx = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 0)
+    jdx = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_t), 1)
+    cum = jax.lax.dot_general(
+        (idx >= jdx).astype(jnp.float32), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     dec_in = jnp.exp(cum - lw)                # decay up to t-1
     r_dec = r * dec_in
     # carried-state contribution
@@ -49,8 +56,6 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     att = jax.lax.dot_general(
         r_dec, kin, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)   # (block_t, block_t)
-    idx = jax.lax.broadcasted_iota(jnp.int32, att.shape, 0)
-    jdx = jax.lax.broadcasted_iota(jnp.int32, att.shape, 1)
     att = jnp.where(idx > jdx, att, 0.0)
     o_intra = jax.lax.dot_general(
         att, v, (((1,), (0,)), ((), ())),
@@ -61,9 +66,17 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_ref, *,
     o_ref[0, 0] = (o_state + o_intra + o_diag).astype(o_ref.dtype)
 
     # state update to the end of the chunk
-    dec_all = jnp.exp(cum[-1])                        # (D,)
-    k_end = k * jnp.exp(cum[-1][None, :] - cum)
-    s_ref[...] = S * dec_all[:, None] + jax.lax.dot_general(
+    last = cum[block_t - 1:]                          # (1, D)
+    k_end = k * jnp.exp(last - cum)
+    # diag(exp(last)) @ S scales S's rows without a lane->sublane relayout
+    d = S.shape[0]
+    di = jax.lax.broadcasted_iota(jnp.int32, (d, d), 0)
+    dj = jax.lax.broadcasted_iota(jnp.int32, (d, d), 1)
+    dec_diag = jnp.where(di == dj, jnp.exp(last), 0.0)
+    s_ref[...] = jax.lax.dot_general(
+        dec_diag, S, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32) + jax.lax.dot_general(
         k_end, v, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
@@ -103,13 +116,15 @@ def rwkv6_scan(
         functools.partial(_rwkv6_kernel, block_t=block_t),
         grid=(B, H, nt),
         in_specs=[spec, spec, spec, spec,
-                  pl.BlockSpec((1, D), lambda b, h, ti: (h, 0))],
+                  # u as (H, 1, D): the block's last two dims equal the
+                  # array's, as Mosaic requires
+                  pl.BlockSpec((1, 1, D), lambda b, h, ti: (h, 0, 0))],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Tp, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(rm, km, vm, lw, u.astype(jnp.float32))
+    )(rm, km, vm, lw, u.astype(jnp.float32)[:, None, :])
     o = jnp.moveaxis(o, 1, 2)[:, :T]
 
     # final state is recomputed cheaply on the host path when needed by

@@ -100,8 +100,6 @@ def run_dryrun(
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         # instruction-level re-derivation: XLA's cost_analysis counts while
         # (layer-scan) bodies once; analyze_hlo multiplies by trip counts

@@ -19,11 +19,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 
-try:                                   # jax >= 0.5 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                 # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 Params = Dict[str, Any]
 
 Q_CHUNK = 512          # query chunk for blockwise attention
@@ -490,7 +485,7 @@ def moe_block(params: Params, cfg: ModelConfig, x: jnp.ndarray,
         "w_up": P(mi.model_axis, data_axes if use_wtp else None, None),
         "w_down": P(mi.model_axis, data_axes if use_wtp else None, None),
     }
-    y = _shard_map(
+    y = jax.shard_map(
         local_fn,
         mesh=mi.mesh,
         in_specs=(

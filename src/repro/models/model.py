@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import ATTN, LOCAL_ATTN, RGLRU, RWKV6, ModelConfig
 from repro.models import layers as L
@@ -60,7 +61,21 @@ def _split_layers(cfg: ModelConfig) -> Tuple[int, int]:
     return n_full, n_tail
 
 
-def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
+def init_params(key, cfg: ModelConfig, dtype=jnp.float32,
+                device: Optional[jax.Device] = None) -> Params:
+    """Random weights for ``cfg``, built in one jitted program.
+
+    The layers of each scanned pattern position are made by vmapping the
+    block init over their keys, so the stacked leaves are written once:
+    no per-layer copies are held beside the stack.  ``device`` places the
+    output there directly (the default device when None).
+    """
+    out = SingleDeviceSharding(device) if device is not None else None
+    return jax.jit(functools.partial(_init_params, cfg=cfg, dtype=dtype),
+                   out_shardings=out)(key)
+
+
+def _init_params(key, cfg: ModelConfig, dtype) -> Params:
     n_full, n_tail = _split_layers(cfg)
     plen = len(cfg.block_pattern)
     keys = jax.random.split(key, 4)
@@ -77,17 +92,14 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
         params["frontend"] = jax.random.normal(
             keys[2], (cfg.frontend_dim, cfg.d_model), dtype) * 0.02
 
+    # layer l = c * plen + pos for cycle c, then the tail layers
     layer_keys = jax.random.split(keys[3], cfg.num_layers)
-    scan_params: Dict[str, Params] = {}
-    for pos in range(plen):
-        kind = cfg.block_pattern[pos]
-        per_cycle = [
-            _init_block(layer_keys[c * plen + pos], cfg, kind, dtype)
-            for c in range(n_full)
-        ]
-        scan_params[f"pos{pos}"] = jax.tree.map(
-            lambda *xs: jnp.stack(xs), *per_cycle)
-    params["layers_scan"] = scan_params
+    params["layers_scan"] = {
+        f"pos{pos}": jax.vmap(functools.partial(
+            _init_block, cfg=cfg, kind=cfg.block_pattern[pos], dtype=dtype))(
+                layer_keys[pos:n_full * plen:plen])
+        for pos in range(plen)
+    }
     params["layers_tail"] = tuple(
         _init_block(layer_keys[n_full * plen + i], cfg,
                     cfg.block_pattern[i % plen], dtype)
@@ -99,42 +111,45 @@ def init_params(key, cfg: ModelConfig, dtype=jnp.float32) -> Params:
 # --------------------------------------------------------------------------- #
 # Cache
 # --------------------------------------------------------------------------- #
-def _block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int,
-                 dtype) -> Params:
-    if kind == ATTN:
-        shape = (batch, max_len, cfg.num_kv_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
-    if kind == LOCAL_ATTN:
-        w = cfg.sliding_window
-        shape = (batch, w, cfg.num_kv_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+def _block_cache(cfg: ModelConfig, kind: str, lead: Tuple[int, ...],
+                 batch: int, max_len: int, dtype, device) -> Params:
+    """Zero cache of one block kind; ``lead`` prefixes every leaf's shape
+    (the layer axis of scanned blocks)."""
+    def zeros(shape, dt=dtype):
+        return jnp.zeros(lead + shape, dt, device=device)
+
+    if kind in (ATTN, LOCAL_ATTN):
+        s = max_len if kind == ATTN else cfg.sliding_window
+        shape = (batch, s, cfg.num_kv_heads, cfg.head_dim)
+        return {"k": zeros(shape), "v": zeros(shape)}
     if kind == RGLRU:
         return {
-            "conv": jnp.zeros((batch, L.CONV_WIDTH - 1, cfg.d_model), dtype),
-            "h": jnp.zeros((batch, cfg.d_model), dtype),
+            "conv": zeros((batch, L.CONV_WIDTH - 1, cfg.d_model)),
+            "h": zeros((batch, cfg.d_model)),
         }
     if kind == RWKV6:
         return {
-            "shift": jnp.zeros((batch, cfg.d_model), dtype),
-            "state": jnp.zeros(
-                (batch, cfg.num_heads, cfg.head_dim, cfg.head_dim),
-                jnp.float32),
+            "shift": zeros((batch, cfg.d_model)),
+            "state": zeros((batch, cfg.num_heads, cfg.head_dim, cfg.head_dim),
+                           jnp.float32),
         }
     raise ValueError(kind)  # pragma: no cover
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int,
-               dtype=jnp.float32) -> Params:
+               dtype=jnp.float32, device: Optional[jax.Device] = None
+               ) -> Params:
+    """Zero decode cache, allocated on ``device`` (default device if None)."""
     n_full, n_tail = _split_layers(cfg)
     plen = len(cfg.block_pattern)
-    scan_cache = {}
-    for pos in range(plen):
-        kind = cfg.block_pattern[pos]
-        one = _block_cache(cfg, kind, batch, max_len, dtype)
-        scan_cache[f"pos{pos}"] = jax.tree.map(
-            lambda x: jnp.broadcast_to(x, (n_full,) + x.shape).copy(), one)
+    scan_cache = {
+        f"pos{pos}": _block_cache(cfg, cfg.block_pattern[pos], (n_full,),
+                                  batch, max_len, dtype, device)
+        for pos in range(plen)
+    }
     tail_cache = tuple(
-        _block_cache(cfg, cfg.block_pattern[i % plen], batch, max_len, dtype)
+        _block_cache(cfg, cfg.block_pattern[i % plen], (), batch, max_len,
+                     dtype, device)
         for i in range(n_tail)
     )
     return {"scan": scan_cache, "tail": tail_cache}

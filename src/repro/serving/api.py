@@ -27,10 +27,12 @@ class EcoServeAPI:
 
     def __init__(self, cfg: ModelConfig, n_instances: int = 2,
                  slo: SLO = SLO(ttft=60.0, tpot=10.0),
-                 econf: EngineConfig = EngineConfig(), seed: int = 0):
+                 econf: EngineConfig = EngineConfig(), seed: int = 0,
+                 cost_model=None):
         self.cfg = cfg
         self.tok = ByteTokenizer(cfg.vocab_size)
-        self.server = PaDGServer(cfg, n_instances, slo, econf, seed=seed)
+        self.server = PaDGServer(cfg, n_instances, slo, econf, seed=seed,
+                                 cost_model=cost_model)
         self._stream_cb: Optional[Callable[[int, int], None]] = None
 
     def generate(self, prompts: List[str], max_new_tokens: int = 16,

@@ -1,15 +1,17 @@
 """Real-execution serving engine: continuous batching over an actual JAX
-model (runs a reduced config on CPU; the same code drives TPU instances).
+model on one device (a TPU chip; reduced configs run on the CPU in tests).
 
 One ``ServingEngine`` is one PaDG *instance*: it owns params, a slotted
-KV cache, and executes prefill/decode slots for the scheduling ``Instance``
-it is attached to.  The scheduler stack (macro instance, Algorithms 1+2,
-mitosis) is exactly the one from ``repro.core`` — durations are measured
-wall-clock instead of predicted, which is what `MeasuredExecutor` adapts.
+KV cache on its device, and executes prefill/decode slots for the
+scheduling ``Instance`` it is attached to.  The scheduler stack (macro
+instance, Algorithms 1+2, mitosis) is exactly the one from
+``repro.core`` — durations are measured wall-clock instead of predicted,
+which is what `MeasuredExecutor` adapts.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -21,7 +23,8 @@ from repro.configs.base import ModelConfig
 from repro.core.instance import Instance
 from repro.core.request import Request, RequestState
 from repro.models import forward, grow_cache, init_cache, init_params
-from repro.models.layers import MeshInfo
+from repro.simulator.cost_model import (HARDWARE_BY_DEVICE_KIND,
+                                        InstanceCostModel)
 
 
 @dataclasses.dataclass
@@ -105,43 +108,77 @@ class MeasuredExecutor:
                                     + self._decode_per_ctx * ctx_sum)
 
 
+def _prefill_step(params, toks, *, cfg: ModelConfig):
+    logits, cache = forward(params, cfg, {"tokens": toks}, return_cache=True)
+    return logits[:, -1], cache
+
+
+def _decode_step(params, cache, toks, lengths, *, cfg: ModelConfig):
+    logits, cache = forward(params, cfg, {"tokens": toks},
+                            cache=cache, cache_len=lengths)
+    return logits[:, 0], cache
+
+
+def serving_steps(cfg: ModelConfig):
+    """The jitted programs a ``ServingEngine`` runs: ``prefill(params,
+    toks (1, T))`` -> (last-position logits, cache of T positions) and
+    ``decode(params, cache, toks (B, 1), lengths (B,))`` -> (logits, cache),
+    the cache donated."""
+    return (jax.jit(functools.partial(_prefill_step, cfg=cfg)),
+            jax.jit(functools.partial(_decode_step, cfg=cfg),
+                    donate_argnums=(1,)))
+
+
 class ServingEngine:
     """Slot-based continuous batching with a fixed-shape decode step (no
-    recompilation as requests come and go)."""
+    recompilation as requests come and go).
+
+    Params, cache and step inputs live on ``device`` (the first device
+    when None).  Without a ``cost_model`` the scheduler's seed model is
+    looked up by the device's kind; a kind with no entry raises.
+    """
 
     def __init__(self, cfg: ModelConfig, params=None, seed: int = 0,
                  econf: EngineConfig = EngineConfig(),
-                 cost_model=None, recorder=None):
+                 cost_model=None, recorder=None,
+                 device: Optional[jax.Device] = None):
         assert not cfg.is_encoder, "decode engine serves decoder models"
         self.cfg = cfg
         self.econf = econf
+        self.device = device if device is not None else jax.devices()[0]
+        if cost_model is None:
+            hw = HARDWARE_BY_DEVICE_KIND.get(self.device.device_kind)
+            if hw is None:
+                raise ValueError(
+                    f"no seed cost model for device kind "
+                    f"{self.device.device_kind!r}; pass cost_model=")
+            cost_model = InstanceCostModel(cfg=cfg, hw=hw)
         self.params = params if params is not None else init_params(
-            jax.random.key(seed), cfg, econf.dtype)
+            jax.random.key(seed), cfg, econf.dtype, device=self.device)
         B, S = econf.max_batch, econf.max_seq_len
-        self.cache = init_cache(cfg, B, max_len=S, dtype=econf.dtype)
-        self.tokens = jnp.zeros((B, 1), jnp.int32)
+        self.cache = init_cache(cfg, B, max_len=S, dtype=econf.dtype,
+                                device=self.device)
+        self.tokens = jnp.zeros((B, 1), jnp.int32, device=self.device)
         self.lengths = np.zeros(B, np.int32)          # context per slot
         self.slot_req: List[Optional[Request]] = [None] * B
-        if cost_model is None:
-            from repro.simulator.cost_model import (InstanceCostModel,
-                                                    TPU_V5E_SIM)
-            cost_model = InstanceCostModel(cfg=cfg, hw=TPU_V5E_SIM)
         self.executor = MeasuredExecutor(seed_model=cost_model)
         self.recorder = recorder      # optional CalibrationRecorder
+        self.prefill_fn, self.decode_fn = serving_steps(cfg)
 
-        self._prefill_fn = jax.jit(self._prefill_impl)
-        self._decode_fn = jax.jit(self._decode_impl, donate_argnums=(1,))
-
-    # --------------------------------------------------------------- #
-    def _prefill_impl(self, params, toks):
-        logits, cache = forward(params, self.cfg, {"tokens": toks},
-                                return_cache=True)
-        return logits[:, -1], cache
-
-    def _decode_impl(self, params, cache, toks, lengths):
-        logits, cache = forward(params, self.cfg, {"tokens": toks},
-                                cache=cache, cache_len=lengths)
-        return logits[:, 0], cache
+    def warmup(self, prompt_lens) -> None:
+        """Compile the prefill for each prompt length, the admission path
+        and the decode step, outside any measured or scheduled time.
+        Leaves every slot free and the executor untouched."""
+        for n in sorted(set(prompt_lens)):
+            toks = jax.device_put(np.zeros((1, n), np.int32), self.device)
+            _, pcache = self.prefill_fn(self.params, toks)
+            _merge_slot(self.cfg, self.cache,
+                        grow_cache(self.cfg, pcache, self.econf.max_seq_len),
+                        0)
+        _, self.cache = self.decode_fn(
+            self.params, self.cache, self.tokens,
+            jax.device_put(self.lengths, self.device))
+        jax.block_until_ready(self.cache)
 
     # --------------------------------------------------------------- #
     def free_slots(self) -> List[int]:
@@ -155,8 +192,9 @@ class ServingEngine:
         slot = slots[0]
         prompt = req.prompt_tokens
         t0 = time.perf_counter()
-        toks = jnp.asarray(np.array(prompt, np.int32))[None, :]
-        logits, pcache = self._prefill_fn(self.params, toks)
+        toks = jax.device_put(np.asarray(prompt, np.int32)[None, :],
+                              self.device)
+        logits, pcache = self.prefill_fn(self.params, toks)
         first = int(jnp.argmax(logits[0]))
         pcache = grow_cache(self.cfg, pcache, self.econf.max_seq_len)
         self.cache = _merge_slot(self.cfg, self.cache, pcache, slot)
@@ -178,8 +216,8 @@ class ServingEngine:
         if not occupied:
             return {}
         t0 = time.perf_counter()
-        lengths = jnp.asarray(self.lengths)
-        logits, self.cache = self._decode_fn(
+        lengths = jax.device_put(self.lengths, self.device)
+        logits, self.cache = self.decode_fn(
             self.params, self.cache, self.tokens, lengths)
         new_tokens = np.asarray(jnp.argmax(logits, axis=-1))
         dt = time.perf_counter() - t0

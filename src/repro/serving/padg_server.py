@@ -101,8 +101,10 @@ class RealEcoServeSystem(EcoServeSystem):
 class PaDGServer:
     """Real-execution EcoServe server.
 
-    ``backend="real"`` builds one jax ``ServingEngine`` per instance
-    (tiny CPU configs by default); ``backend="fake"`` uses the
+    ``backend="real"`` builds one jax ``ServingEngine`` per instance,
+    instance *i* on ``jax.devices()[i]`` — one instance per chip, never
+    two on one (``cost_model`` seeds their schedulers where the device
+    kind has no built-in profile); ``backend="fake"`` uses the
     deterministic ``FakeEngine`` (requires an explicit ``executor`` model
     — there is nothing to measure) for conformance tests and synthetic
     calibration runs.
@@ -111,7 +113,7 @@ class PaDGServer:
     def __init__(self, cfg: Optional[ModelConfig], n_instances: int,
                  slo: SLO, econf=None, seed: int = 0,
                  backend: str = "real", executor=None, recorder=None,
-                 true_model=None):
+                 true_model=None, cost_model=None):
         if econf is None:
             # imported lazily: the fake backend (conformance tests,
             # synthetic calibration) must not pull jax
@@ -121,11 +123,19 @@ class PaDGServer:
         self.slo = slo
         self._shutdown = False
         engines, executors = [], []
+        if backend == "real":
+            import jax
+            devices = jax.devices()
+            if n_instances > len(devices):
+                raise ValueError(
+                    f"{n_instances} one-device instances need as many "
+                    f"devices; {len(devices)} available")
         for i in range(n_instances):
             if backend == "real":
                 from repro.serving.engine import ServingEngine
                 eng = ServingEngine(cfg, seed=seed, econf=econf,
-                                    recorder=recorder)
+                                    cost_model=cost_model,
+                                    recorder=recorder, device=devices[i])
                 engines.append(RealEngineBackend(eng))
                 executors.append(executor if executor is not None
                                  else eng.executor)

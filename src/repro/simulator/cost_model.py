@@ -61,6 +61,11 @@ TPU_V5E_SIM = HardwareProfile(
     intra_node_bw=50e9, inter_node_bw=25e9 / 8, devices_per_node=256,
     prefill_eff=0.55, decode_bw_eff=0.80)
 
+# Seed profile of a live engine, keyed by ``jax.Device.device_kind``.
+# Peaks from Google Cloud's "TPU v5e" documentation (197 TFLOP/s bf16,
+# 819 GB/s, 16 GB HBM per chip); the efficiencies are TPU_V5E_SIM's.
+HARDWARE_BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E_SIM}
+
 
 @dataclasses.dataclass(frozen=True)
 class _Consts:

@@ -15,7 +15,8 @@ from repro.serving.calibration import CalibrationRecorder
 from repro.serving.engine import (EngineConfig, MeasuredExecutor,
                                   ServingEngine)
 from repro.serving.padg_server import PaDGServer
-from repro.simulator.cost_model import FittedExecutor
+from repro.simulator.cost_model import (TPU_V5E_SIM, FittedExecutor,
+                                        InstanceCostModel)
 
 
 def tiny_cfg():
@@ -23,6 +24,11 @@ def tiny_cfg():
     return dataclasses.replace(cfg, num_layers=2, d_model=128, num_heads=2,
                                num_kv_heads=1, head_dim=64, d_ff=256,
                                vocab_size=300)
+
+
+def seed_model(cfg):
+    """The CPU has no seed profile of its own: pass the v5e one."""
+    return InstanceCostModel(cfg=cfg, hw=TPU_V5E_SIM)
 
 
 def greedy_reference(cfg, params, prompt, n_new):
@@ -37,7 +43,7 @@ def greedy_reference(cfg, params, prompt, n_new):
 
 def test_engine_matches_full_forward_greedy():
     cfg = tiny_cfg()
-    eng = ServingEngine(cfg, seed=3,
+    eng = ServingEngine(cfg, seed=3, cost_model=seed_model(cfg),
                         econf=EngineConfig(max_batch=2, max_seq_len=64,
                                            eos_token=-1))
     prompt = [5, 9, 17, 4, 33]
@@ -56,7 +62,7 @@ def test_engine_concurrent_requests_isolated():
     """Two interleaved requests must produce the same tokens as served
     alone (KV-slot isolation under continuous batching)."""
     cfg = tiny_cfg()
-    eng = ServingEngine(cfg, seed=4,
+    eng = ServingEngine(cfg, seed=4, cost_model=seed_model(cfg),
                         econf=EngineConfig(max_batch=2, max_seq_len=64,
                                            eos_token=-1))
     p1, p2 = [7, 3, 11], [21, 9, 2, 40, 8]
@@ -76,8 +82,8 @@ def test_engine_concurrent_requests_isolated():
     assert r2.generated[:5] == solo2
 
 
-@pytest.mark.parametrize("arch", ["llama3-8b", "rwkv6-3b"])
-def test_padg_server_end_to_end(arch):
+def serve_two_instances(arch):
+    """Two engine-backed instances, one per device, serve 6 requests."""
     cfg = get_smoke_config(arch)
     cfg = dataclasses.replace(cfg, num_layers=2, d_model=128,
                               num_heads=2, num_kv_heads=max(1, min(
@@ -85,6 +91,7 @@ def test_padg_server_end_to_end(arch):
                               d_ff=256, vocab_size=300)
     slo = SLO(ttft=60.0, tpot=10.0)   # wall-clock CPU: loose SLOs
     server = PaDGServer(cfg, n_instances=2, slo=slo,
+                        cost_model=seed_model(cfg),
                         econf=EngineConfig(max_batch=2, max_seq_len=48,
                                            eos_token=-1))
     rng = np.random.default_rng(0)
@@ -101,6 +108,24 @@ def test_padg_server_end_to_end(arch):
         assert len(r.generated) == 4
         assert r.finish_time >= r.first_token_time >= 0
     server.shutdown()
+
+
+@pytest.mark.parametrize("arch", ["llama3-8b", "rwkv6-3b"])
+def test_padg_server_end_to_end(arch, on_host_devices):
+    on_host_devices(serve_two_instances, arch, n=2)
+
+
+def test_padg_server_refuses_two_instances_on_one_device():
+    cfg = tiny_cfg()
+    with pytest.raises(ValueError, match="devices"):
+        PaDGServer(cfg, n_instances=len(jax.devices()) + 1,
+                   slo=SLO(ttft=60.0, tpot=10.0), cost_model=seed_model(cfg))
+
+
+def test_engine_without_seed_profile_raises():
+    """The CPU's device kind has no seed profile: no silent default."""
+    with pytest.raises(ValueError, match="cost_model"):
+        ServingEngine(tiny_cfg())
 
 
 # --------------------------------------------------------------------- #
@@ -147,7 +172,7 @@ def test_measured_executor_legacy_fallbacks():
 def test_engine_recorder_captures_op_shapes():
     cfg = tiny_cfg()
     rec = CalibrationRecorder()
-    eng = ServingEngine(cfg, seed=5, recorder=rec,
+    eng = ServingEngine(cfg, seed=5, recorder=rec, cost_model=seed_model(cfg),
                         econf=EngineConfig(max_batch=2, max_seq_len=64,
                                            eos_token=-1))
     prompt = [5, 9, 17, 4]

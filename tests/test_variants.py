@@ -71,9 +71,10 @@ def test_ecoserve_cp_system_runs_and_attains():
     assert m["attainment"] > 0.9
 
 
-def test_serving_api_generate_streaming():
+def serving_api_generate_streaming():
     from repro.serving.api import EcoServeAPI
     from repro.serving.engine import EngineConfig
+    from repro.simulator.cost_model import TPU_V5E_SIM
 
     cfg = get_smoke_config("llama3-8b")
     cfg = dataclasses.replace(cfg, num_layers=2, d_model=128, num_heads=2,
@@ -81,7 +82,8 @@ def test_serving_api_generate_streaming():
                               vocab_size=300)
     api = EcoServeAPI(cfg, n_instances=2,
                       econf=EngineConfig(max_batch=2, max_seq_len=64,
-                                         eos_token=-1))
+                                         eos_token=-1),
+                      cost_model=InstanceCostModel(cfg=cfg, hw=TPU_V5E_SIM))
     streamed = []
     res = api.generate(["hello world", "padg serving"],
                        max_new_tokens=4,
@@ -92,3 +94,8 @@ def test_serving_api_generate_streaming():
         assert r.ttft_s >= 0
         assert isinstance(r.text, str)
     assert len(streamed) == 8
+
+
+def test_serving_api_generate_streaming(on_host_devices):
+    # two instances need two devices
+    on_host_devices(serving_api_generate_streaming, n=2)
