@@ -7,10 +7,16 @@ emits the request lifecycle (arrive / admit / enqueue / drain / finish /
 fail / requeue / migrate), the macro scheduler emits rolling-activation
 rotations and mitosis split/merge, the transport emits per-message fates,
 the fault injector and control loop emit their domain events, and the
-real-path ``CalibrationRecorder`` emits per-op timings.  Everything is a
+served path (``repro.serving``) emits ``span`` events around the work as
+it runs: the event loop's sleeps and each engine step.  Everything is a
 plain tuple ``(etype, t, ...)`` appended to ``tracer.events`` — no
 classes, no dict churn on the hot path; the positional field names live
 in ``repro.obs.export.SCHEMA``.
+
+A span is also written into the JAX profiler's trace when the served
+path hands the tracer an ``annotate`` hook (``TraceAnnotation``), so the
+host's spans and the device's programs share one clock there.  This
+module never imports jax itself.
 
 The default is ``NULL_TRACER`` (``enabled = False``): every emission site
 guards with one attribute read (``trc = self.tracer; if trc.enabled:``),
@@ -26,7 +32,12 @@ engine/system/transport hot paths can import it without cycles.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, List, Optional, Tuple
+
+
+# the one context manager of every span while tracing is off
+NULL_SPAN = contextlib.nullcontext()
 
 
 class NullTracer:
@@ -50,6 +61,9 @@ class NullTracer:
     def now(self) -> float:
         return -1.0
 
+    def span(self, name: str, **stats: Any) -> contextlib.nullcontext:
+        return NULL_SPAN
+
 
 NULL_TRACER = NullTracer()
 
@@ -63,12 +77,17 @@ class Tracer:
     ``decision_log`` compat shim) that never accumulates ``events``.
     ``clock`` supplies timestamps for control-plane emissions that have
     no sim time in scope (mitosis split/merge); ``run_once`` wires it to
-    the engine clock, bare construction stamps ``-1.0``.
+    the engine clock, bare construction stamps ``-1.0``.  ``timeline``
+    times spans (the replay engine wires its own clock, which keeps
+    running while a slot's work runs; ``clock`` when None), and
+    ``annotate`` (``jax.profiler.TraceAnnotation``, given by the served
+    path) writes each span into the profiler's trace as well.
     """
 
     enabled = True
 
-    __slots__ = ("events", "_mirror", "_record", "clock", "meta")
+    __slots__ = ("events", "_mirror", "_record", "clock", "meta",
+                 "timeline", "annotate")
 
     def __init__(self, mirror: Optional[list] = None, record: bool = True,
                  clock: Optional[Callable[[], float]] = None):
@@ -77,6 +96,8 @@ class Tracer:
         self._record = record
         self.clock = clock
         self.meta: dict = {}
+        self.timeline: Optional[Callable[[], float]] = None
+        self.annotate: Optional[Callable[..., Any]] = None
 
     def now(self) -> float:
         """Clock fallback for emissions without a timestamp in scope."""
@@ -174,11 +195,23 @@ class Tracer:
         if self._record:
             self.events.append(("transport", t, what, kind, src, dst))
 
-    # ---------------- real-path op samples (calibration bus) ----------- #
-    def op(self, t: float, what: str, work: int, extra: int,
-           dt: float) -> None:
-        if self._record:
-            self.events.append(("op", t, what, work, extra, dt))
+    # ---------------- spans of the served path ------------------------- #
+    @contextlib.contextmanager
+    def span(self, name: str, **stats: Any):
+        """Time the ``with`` body: appends ``("span", t_start, name, dur,
+        stats)`` on the replay timeline, and while the JAX profiler records
+        (``annotate`` set) the body is a ``name`` host span with ``stats``
+        in its trace."""
+        clock = self.timeline if self.timeline is not None else self.now
+        ann = (self.annotate(name, **stats) if self.annotate is not None
+               else NULL_SPAN)
+        t0 = clock()
+        try:
+            with ann:
+                yield
+        finally:
+            if self._record:
+                self.events.append(("span", t0, name, clock() - t0, stats))
 
 
 def slot_rids(field) -> Tuple[int, ...]:

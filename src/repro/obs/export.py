@@ -9,9 +9,10 @@ for every JSON-representable payload, which all emission sites keep to.
 
 ``chrome_trace`` renders the events in the Chrome Trace Event JSON
 format Perfetto loads directly (https://ui.perfetto.dev -> open trace):
-slot spans become complete ("X") events on one track per instance,
-request/instance/fault/control/transport events become instants ("i"),
-and the per-instance state samples become counter ("C") tracks (KV
+slot spans and the served path's spans become complete ("X") events on
+one track per instance (a span with no ``iid`` stat on the control
+track), request/instance/fault/control/transport events become instants
+("i"), and the per-instance state samples become counter ("C") tracks (KV
 occupancy, queue depth, decode batch utilization, prefill backlog).
 """
 from __future__ import annotations
@@ -42,7 +43,7 @@ SCHEMA: Dict[str, Tuple[str, ...]] = {
     "fault":     ("kind", "iid"),
     "control":   ("what", "value"),
     "transport": ("what", "kind", "src", "dst"),
-    "op":        ("what", "work", "extra", "dt"),
+    "span":      ("name", "dur", "stats"),
 }
 
 # fields decoded back to tuples (JSON has no tuple type)
@@ -198,10 +199,16 @@ def chrome_trace(tracer_or_events, meta: dict = None) -> dict:
             what, kind, src, dst = ev[2:]
             instant(f"transport:{what}", t, tid_of(None),
                     {"kind": kind, "src": src, "dst": dst})
+        elif etype == "span":
+            name, dur, stats = ev[2:]
+            out.append({
+                "name": name, "ph": "X", "pid": _PID_SIM,
+                "tid": tid_of(stats.get("iid")), "ts": _us(t),
+                "dur": round(dur * _US, 3), "args": dict(stats)})
         elif etype in ("fail", "migrate"):
             instant(f"request:{etype}", t, tid_of(None),
                     {SCHEMA[etype][0]: ev[2]})
-        # arrive/admit/enqueue/drain/finish/handoff/op stay out of the
+        # arrive/admit/enqueue/drain/finish/handoff stay out of the
         # rendered trace (per-request volume would swamp the UI); they
         # remain in the JSONL for the attribution tooling.
     return {"traceEvents": out, "displayTimeUnit": "ms",

@@ -21,20 +21,12 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.obs.events import NULL_TRACER
 from repro.simulator.cost_model import (FITTED_CONSTANT_FIELDS,  # noqa: F401
                                         FittedExecutor, InstanceCostModel)
 
 
 class CalibrationRecorder:
-    """Accumulates per-op engine timings for fitting and error reports.
-
-    When a flight-recorder ``tracer`` is attached, every sample is also
-    emitted as an ``op`` event — the same bus the simulator runs on, so
-    sim-vs-real disagreement can be localized to a specific op/span
-    rather than a run-level scalar."""
-
-    tracer = NULL_TRACER
+    """Accumulates per-op engine timings for fitting and error reports."""
 
     def __init__(self) -> None:
         self.prefill: List[Tuple[int, float]] = []      # (tokens, dt)
@@ -42,15 +34,9 @@ class CalibrationRecorder:
 
     def record_prefill(self, tokens: int, dt: float) -> None:
         self.prefill.append((int(tokens), float(dt)))
-        trc = self.tracer
-        if trc.enabled:
-            trc.op(trc.now(), "prefill", int(tokens), 0, float(dt))
 
     def record_decode(self, batch: int, ctx_sum: int, dt: float) -> None:
         self.decode.append((int(batch), int(ctx_sum), float(dt)))
-        trc = self.tracer
-        if trc.enabled:
-            trc.op(trc.now(), "decode", int(batch), int(ctx_sum), float(dt))
 
     def __len__(self) -> int:
         return len(self.prefill) + len(self.decode)

@@ -11,7 +11,6 @@ which is what `MeasuredExecutor` adapts.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Dict, List, Optional
 
@@ -23,6 +22,7 @@ from repro.configs.base import ModelConfig
 from repro.core.instance import Instance
 from repro.core.request import Request, RequestState
 from repro.models import forward, grow_cache, init_cache, init_params
+from repro.obs.events import NULL_SPAN, NULL_TRACER
 from repro.simulator.cost_model import (HARDWARE_BY_DEVICE_KIND,
                                         InstanceCostModel)
 
@@ -123,10 +123,15 @@ def serving_steps(cfg: ModelConfig):
     """The jitted programs a ``ServingEngine`` runs: ``prefill(params,
     toks (1, T))`` -> (last-position logits, cache of T positions) and
     ``decode(params, cache, toks (B, 1), lengths (B,))`` -> (logits, cache),
-    the cache donated."""
-    return (jax.jit(functools.partial(_prefill_step, cfg=cfg)),
-            jax.jit(functools.partial(_decode_step, cfg=cfg),
-                    donate_argnums=(1,)))
+    the cache donated.  Named functions, so that a profiler trace shows
+    the programs as ``jit_prefill_step`` and ``jit_decode_step``."""
+    def prefill_step(params, toks):
+        return _prefill_step(params, toks, cfg=cfg)
+
+    def decode_step(params, cache, toks, lengths):
+        return _decode_step(params, cache, toks, lengths, cfg=cfg)
+
+    return jax.jit(prefill_step), jax.jit(decode_step, donate_argnums=(1,))
 
 
 class ServingEngine:
@@ -136,7 +141,15 @@ class ServingEngine:
     Params, cache and step inputs live on ``device`` (the first device
     when None).  Without a ``cost_model`` the scheduler's seed model is
     looked up by the device's kind; a kind with no entry raises.
+
+    With a flight recorder attached (``tracer``, ``iid`` its instance),
+    each step is a span: ``step.prefill`` (prompt in, first token out),
+    ``step.admit`` (the prefill cache copied into the request's slot) and
+    ``step.decode`` (one decode iteration, sampled tokens fed back).
     """
+
+    tracer = NULL_TRACER
+    iid = 0
 
     def __init__(self, cfg: ModelConfig, params=None, seed: int = 0,
                  econf: EngineConfig = EngineConfig(),
@@ -191,21 +204,27 @@ class ServingEngine:
         assert slots, "no free decode slot"
         slot = slots[0]
         prompt = req.prompt_tokens
+        trc = self.tracer
+        on = trc.enabled
         t0 = time.perf_counter()
-        toks = jax.device_put(np.asarray(prompt, np.int32)[None, :],
-                              self.device)
-        logits, pcache = self.prefill_fn(self.params, toks)
-        first = int(jnp.argmax(logits[0]))
-        pcache = grow_cache(self.cfg, pcache, self.econf.max_seq_len)
-        self.cache = _merge_slot(self.cfg, self.cache, pcache, slot)
-        dt = time.perf_counter() - t0
+        with (trc.span("step.prefill", iid=self.iid, rid=req.rid,
+                       tokens=len(prompt)) if on else NULL_SPAN):
+            toks = jax.device_put(np.asarray(prompt, np.int32)[None, :],
+                                  self.device)
+            logits, pcache = self.prefill_fn(self.params, toks)
+            first = int(jnp.argmax(logits[0]))
+        with (trc.span("step.admit", iid=self.iid, rid=req.rid, slot=slot)
+              if on else NULL_SPAN):
+            pcache = grow_cache(self.cfg, pcache, self.econf.max_seq_len)
+            self.cache = _merge_slot(self.cfg, self.cache, pcache, slot)
+            dt = time.perf_counter() - t0
+            self.tokens = self.tokens.at[slot, 0].set(first)
         self.executor.observe_prefill(len(prompt), dt)
         if self.recorder is not None:
             self.recorder.record_prefill(len(prompt), dt)
 
         self.lengths[slot] = len(prompt)
         self.slot_req[slot] = req
-        self.tokens = self.tokens.at[slot, 0].set(first)
         req.generated = [first]
         return first
 
@@ -215,32 +234,35 @@ class ServingEngine:
         occupied = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not occupied:
             return {}
-        t0 = time.perf_counter()
-        lengths = jax.device_put(self.lengths, self.device)
-        logits, self.cache = self.decode_fn(
-            self.params, self.cache, self.tokens, lengths)
-        new_tokens = np.asarray(jnp.argmax(logits, axis=-1))
-        dt = time.perf_counter() - t0
         ctx_sum = int(sum(self.lengths[i] for i in occupied))
-        self.executor.observe_decode(dt, batch=len(occupied),
-                                     ctx_sum=ctx_sum)
-        if self.recorder is not None:
-            self.recorder.record_decode(len(occupied), ctx_sum, dt)
-
+        trc = self.tracer
         out: Dict[int, int] = {}
-        for i in occupied:
-            tok = int(new_tokens[i])
-            self.lengths[i] += 1
-            out[i] = tok
-            req = self.slot_req[i]
-            req.generated.append(tok)
-            self.tokens = self.tokens.at[i, 0].set(tok)
-            done = (tok == self.econf.eos_token
-                    or len(req.generated) >= req.output_len
-                    or self.lengths[i] >= self.econf.max_seq_len - 1)
-            if done:
-                self.slot_req[i] = None
-                self.lengths[i] = 0
+        with (trc.span("step.decode", iid=self.iid, batch=len(occupied),
+                       ctx=ctx_sum) if trc.enabled else NULL_SPAN):
+            t0 = time.perf_counter()
+            lengths = jax.device_put(self.lengths, self.device)
+            logits, self.cache = self.decode_fn(
+                self.params, self.cache, self.tokens, lengths)
+            new_tokens = np.asarray(jnp.argmax(logits, axis=-1))
+            dt = time.perf_counter() - t0
+            self.executor.observe_decode(dt, batch=len(occupied),
+                                         ctx_sum=ctx_sum)
+            if self.recorder is not None:
+                self.recorder.record_decode(len(occupied), ctx_sum, dt)
+
+            for i in occupied:
+                tok = int(new_tokens[i])
+                self.lengths[i] += 1
+                out[i] = tok
+                req = self.slot_req[i]
+                req.generated.append(tok)
+                self.tokens = self.tokens.at[i, 0].set(tok)
+                done = (tok == self.econf.eos_token
+                        or len(req.generated) >= req.output_len
+                        or self.lengths[i] >= self.econf.max_seq_len - 1)
+                if done:
+                    self.slot_req[i] = None
+                    self.lengths[i] = 0
         return out
 
     def release(self, req: Request) -> None:

@@ -123,6 +123,7 @@ class PaDGServer:
         self.slo = slo
         self._shutdown = False
         engines, executors = [], []
+        self.serving_engines = []     # the jax ServingEngines, by instance
         if backend == "real":
             import jax
             devices = jax.devices()
@@ -136,6 +137,8 @@ class PaDGServer:
                 eng = ServingEngine(cfg, seed=seed, econf=econf,
                                     cost_model=cost_model,
                                     recorder=recorder, device=devices[i])
+                eng.iid = i
+                self.serving_engines.append(eng)
                 engines.append(RealEngineBackend(eng))
                 executors.append(executor if executor is not None
                                  else eng.executor)
@@ -167,8 +170,10 @@ class PaDGServer:
         time on the default wall clock; pass a ``VirtualClock`` for a
         deterministic (conformance) replay.  ``tracer`` attaches a
         flight recorder to the served run — the same
-        ``repro.obs.Tracer`` the simulator uses, with the recorder's
-        per-op samples riding the same bus."""
+        ``repro.obs.Tracer`` the simulator uses, with the served path's
+        spans (the event loop's sleeps, each engine step) riding the same
+        bus and, on the real backend, written into the JAX profiler's
+        trace while it records."""
         usable = self.econf.max_seq_len - 2
         accepted, rejected = [], []
         for r in requests:
@@ -187,8 +192,13 @@ class PaDGServer:
             self.system.decision_log = log
         if tracer is not None:
             attach_tracer(tracer, engine=engine, system=self.system)
-            if self.recorder is not None:
-                self.recorder.tracer = tracer
+            # the server's own engines, not ``inst.engine``, which a
+            # caller may have wrapped
+            for eng in self.serving_engines:
+                eng.tracer = tracer
+            if self.serving_engines:
+                import jax
+                tracer.annotate = jax.profiler.TraceAnnotation
         try:
             finished = engine.run(accepted, horizon=horizon)
         finally:

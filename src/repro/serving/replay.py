@@ -20,7 +20,10 @@ supplies those two axes:
   execute the slot, reconciles engine-side early finishes (EOS, seq cap)
   with the scheduler's token accounting, then applies the normal
   completion path — so admission, routing and slot ordering are decided
-  by exactly the code the simulator runs.
+  by exactly the code the simulator runs.  Traced, its sleeps are
+  ``serve.pace`` spans (a scheduled slot's work waits for the slot's
+  predicted end) or ``serve.wait`` spans (nothing scheduled: waiting for
+  an arrival or a forced admission).
 """
 from __future__ import annotations
 
@@ -259,26 +262,51 @@ class ReplayEngine(SimulationEngine):
                 backend.release(r)
 
     # ------------------------------------------------------------------ #
+    def _sleep_until(self, t: float) -> None:
+        """The clock's sleep until timeline time ``t``; traced, a sleep is a
+        ``serve.pace`` span while some instance holds a scheduled slot
+        whose work has not run (stats: the earliest such slot's ``iid``
+        and ``kind``), else a ``serve.wait`` span."""
+        trc = self.tracer
+        if not trc.enabled or t <= self.clock.now():
+            self.clock.sleep_until(t)
+            return
+        pending = [ev for ev in self.heap if ev.fn == self._complete_slot]
+        if pending:
+            inst, kind = min(pending).args[:2]
+            span = trc.span("serve.pace", iid=inst.iid, kind=kind)
+        else:
+            span = trc.span("serve.wait")
+        with span:
+            self.clock.sleep_until(t)
+
     def run(self, requests: List[Request],
             horizon: float = float("inf")) -> List[Request]:
         arrivals = sorted(requests, key=lambda r: r.arrival_time)
         i, n = 0, len(arrivals)
         heap = self.heap
         self.clock.start()
+        trc = self.tracer
+        if trc.enabled:
+            # spans run on this clock; the instant marks its zero in the
+            # profiler's trace
+            trc.timeline = self.clock.now
+            with trc.span("serve.start"):
+                pass
         import heapq
         while True:
             t_arr = arrivals[i].arrival_time if i < n else None
             if heap and (t_arr is None or heap[0].time < t_arr):
                 if heap[0].time > horizon:
                     break
+                self._sleep_until(heap[0].time)
                 ev = heapq.heappop(heap)
-                self.clock.sleep_until(ev.time)
                 self.now = max(self.now, ev.time)
                 ev.fn(*ev.args)
             elif t_arr is not None:
                 if t_arr > horizon:
                     break
-                self.clock.sleep_until(t_arr)
+                self._sleep_until(t_arr)
                 self.now = max(self.now, t_arr)
                 req = arrivals[i]
                 i += 1
@@ -317,12 +345,12 @@ class ReplayEngine(SimulationEngine):
             t = max(self.now, t_force) + 1e-9
             if t > horizon:
                 return
-            self.clock.sleep_until(t)
+            self._sleep_until(t)
             self.now = max(self.now, t)
             system._drain_queue(self.now, self)
             while self.heap and self.heap[0].time <= horizon:
+                self._sleep_until(self.heap[0].time)
                 ev = heapq.heappop(self.heap)
-                self.clock.sleep_until(ev.time)
                 self.now = max(self.now, ev.time)
                 ev.fn(*ev.args)
             if len(queue) >= before:

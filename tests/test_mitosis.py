@@ -6,7 +6,7 @@ import pytest
 
 from repro.core.instance import Instance
 from repro.core.mitosis import InstanceHandler, OverallScheduler, \
-    StaleHandlerError, register_instance, registry_size
+    StaleHandlerError, register_instance, registry_size, unregister_instance
 from repro.core.slo import SLO
 
 
@@ -147,8 +147,13 @@ def test_dead_instance_handler_resolve_raises():
     inst = make_inst(3001)
     h = InstanceHandler.for_instance(inst)
     inst.alive = False
-    with pytest.raises(StaleHandlerError):
-        h.resolve()
+    try:
+        with pytest.raises(StaleHandlerError):
+            h.resolve()
+    finally:
+        # the registry is process-global: a dead entry left here fails
+        # the fault tests' no-dead-actor check in the same process
+        unregister_instance(inst)
 
 
 def test_migration_does_not_interrupt_execution():
