@@ -3,8 +3,8 @@
 The server IS the simulator's scheduling stack: requests flow through an
 ``EcoServeSystem`` (Algorithm 1 routing over macro instances, Algorithm 2
 admission constraints, timeout-forced queueing) driven by a
-``repro.serving.replay.ReplayEngine`` — a ``SimulationEngine`` whose slot
-completions additionally execute on each instance's attached engine
+``repro.serving.replay.ReplayEngine`` — a ``SimulationEngine`` whose slots
+additionally execute, as they start, on each instance's attached engine
 backend (the jax ``ServingEngine`` or the deterministic ``FakeEngine``)
 and whose timeline can follow a wall clock.  Because both stacks run the
 identical admission/routing/slot code, the sim-to-real conformance suite

@@ -15,15 +15,16 @@ supplies those two axes:
   model's timings into a CalibrationRecorder), and an adapter over the
   jax ``ServingEngine`` with the same run_prefill/run_decode/release
   protocol.
-- ``ReplayEngine``: a ``SimulationEngine`` subclass that, at every slot
-  completion, first lets the instance's attached backend actually
-  execute the slot, reconciles engine-side early finishes (EOS, seq cap)
-  with the scheduler's token accounting, then applies the normal
-  completion path — so admission, routing and slot ordering are decided
-  by exactly the code the simulator runs.  Traced, its sleeps are
-  ``serve.pace`` spans (a scheduled slot's work waits for the slot's
-  predicted end) or ``serve.wait`` spans (nothing scheduled: waiting for
-  an arrival or a forced admission).
+- ``ReplayEngine``: a ``SimulationEngine`` subclass that, as a slot
+  starts, lets the instance's attached backend execute it at once,
+  reconciles engine-side early finishes (EOS, seq cap) with the
+  scheduler's token accounting, and completes the slot at the later of
+  its predicted end and the clock's time when the work returned; the
+  completion is the simulator's own — so admission, routing and slot
+  ordering are decided by exactly the code the simulator runs.  Traced,
+  its sleeps are ``serve.pace`` spans (a slot's work is done and its
+  predicted end has not come yet) or ``serve.wait`` spans (nothing
+  scheduled: waiting for an arrival or a forced admission).
 """
 from __future__ import annotations
 
@@ -224,11 +225,15 @@ class ReplayEngine(SimulationEngine):
     """SimulationEngine that executes slots on each instance's attached
     engine backend (``inst.engine``) and paces the timeline by a clock.
 
-    With a ``VirtualClock`` (the default when ``clock`` is None) and an
-    analytic executor model, a replay is a plain discrete-event run plus
-    real token generation — decision-for-decision identical to the
-    simulator, which is the sim-to-real conformance property.  With a
-    ``WallClock``, measured execution time that overruns the modeled slot
+    A slot's work runs when the slot starts, and the slot completes at
+    the later of its predicted end and the clock's time when the work
+    returned: the loop sleeps only while the prediction outlasts the
+    work, and no token is stamped before the host had it.  With a
+    ``VirtualClock`` (the default when ``clock`` is None) the work takes
+    no time, so with an analytic executor model a replay is a plain
+    discrete-event run plus real token generation — decision-for-decision
+    identical to the simulator, which is the sim-to-real conformance
+    property.  With a ``WallClock``, work that overruns the modeled slot
     duration pushes the timeline forward (never backward), so SLO math
     reflects reality.
     """
@@ -238,35 +243,33 @@ class ReplayEngine(SimulationEngine):
         self.clock = clock if clock is not None else VirtualClock()
 
     # ------------------------------------------------------------------ #
-    def _complete_slot(self, inst, kind, reqs, t_end):
+    def _start_slot(self, inst, kind, reqs, t_end):
         backend = getattr(inst, "engine", None)
-        if backend is not None and inst.alive:
-            if kind == "prefill":
-                backend.run_prefill(reqs)
-            else:
-                for r in backend.run_decode(reqs):
-                    # engine finished early (EOS or per-slot seq cap):
-                    # clamp the scheduler's target so both sides agree
-                    # this request is done
-                    r.output_len = len(r.generated)
-            t_real = self.clock.now()
-            if t_real > t_end:
-                t_end = t_real
-                self.now = t_real
-        n0 = len(self.finished)
-        super()._complete_slot(inst, kind, reqs, t_end)
-        if backend is not None:
-            # requests the scheduler finished that still hold an engine
-            # slot (e.g. one-token outputs done at prefill)
-            for r in self.finished[n0:]:
+        if backend is None:
+            return t_end
+        if kind == "prefill":
+            backend.run_prefill(reqs)
+        else:
+            for r in backend.run_decode(reqs):
+                # engine finished early (EOS or per-slot seq cap): clamp
+                # the scheduler's target so both sides agree this
+                # request is done
+                r.output_len = len(r.generated)
+        for r in reqs:
+            if len(r.generated) >= r.output_len:
+                # the scheduler finishes it at this slot's completion,
+                # which starts the next slot's work: free the engine
+                # slot it still holds (a one-token output done at
+                # prefill) before then
                 backend.release(r)
+        return max(t_end, self.clock.now())
 
     # ------------------------------------------------------------------ #
     def _sleep_until(self, t: float) -> None:
         """The clock's sleep until timeline time ``t``; traced, a sleep is a
-        ``serve.pace`` span while some instance holds a scheduled slot
-        whose work has not run (stats: the earliest such slot's ``iid``
-        and ``kind``), else a ``serve.wait`` span."""
+        ``serve.pace`` span while some instance holds a slot whose work is
+        done and whose predicted end has not come (stats: the earliest
+        such slot's ``iid`` and ``kind``), else a ``serve.wait`` span."""
         trc = self.tracer
         if not trc.enabled or t <= self.clock.now():
             self.clock.sleep_until(t)

@@ -114,8 +114,14 @@ class SimulationEngine:
             trc.slot(self.now, inst, kind, dur, reqs,
                      len(getattr(self.system, "queue", ())))
         self._executing[inst.iid] = True
-        t_end = self.now + dur
+        t_end = self._start_slot(inst, kind, reqs, self.now + dur)
         self.push_call(t_end, self._complete_slot, inst, kind, reqs, t_end)
+
+    def _start_slot(self, inst: Instance, kind: str, reqs: List[Request],
+                    t_end: float) -> float:
+        """Hook run as a slot starts; returns the time the slot completes.
+        A simulated slot completes at its predicted end ``t_end``."""
+        return t_end
 
     def _complete_slot(self, inst: Instance, kind: str,
                        reqs: List[Request], t_end: float) -> None:
