@@ -7,7 +7,6 @@ import sys
 import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import counts  # noqa: E402
 import hooks  # noqa: E402
 
 

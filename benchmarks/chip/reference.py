@@ -1,9 +1,11 @@
-"""Plain float32 reference of the served decoder (the ChatGLM3 family):
-pre-norm RMSNorm blocks, grouped-query attention with qkv bias and rotary
-embeddings on the first half of each head, and a SwiGLU MLP.  Written from
-the published description and sharing no code with the program; it reads
-the benchmark's own weights (``weights.py``), upcast layer by layer so the
-model never needs to fit in float32 at once.
+"""Plain float32 reference of the served decoder: the embedding, each
+layer in the program's order by its architecture's module
+(``archs/<architecture>.py``, ``layer``), the final norm and the output
+head.  Written from the published description and sharing no code with
+the program; it reads the benchmark's own weights (``weights.py``),
+upcast layer by layer so the model never needs to fit in float32 at once.
+The helpers here (``ein``, ``rms``, ``rotary``, ``blocks``,
+``causal_attention``) are for the architecture modules to share.
 
 Every matrix product runs at ``Precision.HIGHEST``: on a TPU a float32
 product otherwise runs in bfloat16 passes.  ``mode="fp8"`` is the control:
@@ -36,18 +38,18 @@ def _fq(x, axis):
     return (x / s).astype(F8).astype(jnp.float32) * s
 
 
-def _ein(spec, a, b, fp8, axes):
+def ein(spec, a, b, fp8, axes):
     if fp8:
         a, b = _fq(a, axes[0]), _fq(b, axes[1])
     return jnp.einsum(spec, a, b, precision=HIGHEST)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     x = x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
     return x * (1.0 + scale)
 
 
-def _rotary(x, pos, rot_dim, theta):
+def rotary(x, pos, rot_dim, theta):
     """Rotate-half rotary embedding on the first ``rot_dim`` features of
     each head; x (T, H, D), pos (T,)."""
     half = rot_dim // 2
@@ -59,7 +61,7 @@ def _rotary(x, pos, rot_dim, theta):
         [a * cos - b * sin, b * cos + a * sin, x[..., rot_dim:]], axis=-1)
 
 
-def _blocks(n: int, most: int) -> int:
+def blocks(n: int, most: int) -> int:
     """The fewest blocks of at most ``most`` that divide n evenly, so no
     whole float32 copy of a large weight is ever held."""
     k = -(-n // most)
@@ -68,73 +70,36 @@ def _blocks(n: int, most: int) -> int:
     return k
 
 
-def _layer(stack, l, x, *, cfg, fp8):
-    def w(*path):
-        a = stack
-        for p in path:
-            a = a[p]
-        return jax.lax.dynamic_index_in_dim(a, l, keepdims=False
-                                            ).astype(jnp.float32)
-
-    T = x.shape[0]
-    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    G = Hq // Hkv
-    rot = D // 2
+def causal_attention(q, k, v, fp8):
+    """Causal attention of q (T, Hq, D) over k, v (T, Hkv, D), each group of
+    Hq / Hkv query heads on one key/value head, one BLOCK of queries at a
+    time; (T, Hq * D)."""
+    T, Hq, D = q.shape
+    Hkv = k.shape[1]
     pos = jnp.arange(T)
-    lin = partial(_ein, "td,de->te", fp8=fp8, axes=(-1, 0))
-
-    h = _rms(x, w("norm1", "scale"), cfg.norm_eps)
-    q = lin(h, w("core", "wq"))
-    k = lin(h, w("core", "wk"))
-    v = lin(h, w("core", "wv"))
-    if cfg.qkv_bias:
-        q = q + w("core", "bq")
-        k = k + w("core", "bk")
-        v = v + w("core", "bv")
-    q = _rotary(q.reshape(T, Hq, D), pos, rot, cfg.rope_theta)
-    k = _rotary(k.reshape(T, Hkv, D), pos, rot, cfg.rope_theta)
-    v = v.reshape(T, Hkv, D)
-    qb = q.reshape(T // BLOCK, BLOCK, Hkv, G, D)
+    qb = q.reshape(T // BLOCK, BLOCK, Hkv, Hq // Hkv, D)
 
     def attend(i):
-        s = _ein("qhgd,shd->hgqs", qb[i], k, fp8, (-1, -1)) * D ** -0.5
+        s = ein("qhgd,shd->hgqs", qb[i], k, fp8, (-1, -1)) * D ** -0.5
         qpos = i * BLOCK + jnp.arange(BLOCK)
         s = jnp.where(pos[None, :] <= qpos[:, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
-        return _ein("hgqs,shd->qhgd", p, v, fp8, (-1, 0))
+        return ein("hgqs,shd->qhgd", p, v, fp8, (-1, 0))
 
-    o = jax.lax.map(attend, jnp.arange(T // BLOCK)).reshape(T, Hq * D)
-    x = x + lin(o, w("core", "wo"))
-
-    h = _rms(x, w("norm2", "scale"), cfg.norm_eps)
-    F = cfg.d_ff
-    nb = _blocks(F, 4096)
-    ffn = stack["ffn"]
-
-    def mlp(acc, j):
-        def cols(a, axis):
-            a = jax.lax.dynamic_index_in_dim(a, l, keepdims=False)
-            return jax.lax.dynamic_slice_in_dim(
-                a, j * (F // nb), F // nb, axis=axis).astype(jnp.float32)
-        g = (jax.nn.silu(lin(h, cols(ffn["w_gate"], 1)))
-             * lin(h, cols(ffn["w_up"], 1)))
-        return acc + lin(g, cols(ffn["w_down"], 0)), None
-
-    out, _ = jax.lax.scan(mlp, jnp.zeros_like(x), jnp.arange(nb))
-    return x + out
+    return jax.lax.map(attend, jnp.arange(T // BLOCK)).reshape(T, Hq * D)
 
 
 def _head(final_scale, head, h, read, *, cfg, fp8):
     """Per row of h: the largest logit, its token, and the logits of the
     tokens in ``read`` (k, n); the vocabulary is taken in column blocks."""
-    h = _rms(h, final_scale.astype(jnp.float32), cfg.norm_eps)
+    h = rms(h, final_scale.astype(jnp.float32), cfg.norm_eps)
     V = head.shape[1]
-    vb = V // _blocks(V, 8192)
+    vb = V // blocks(V, 8192)
 
     def block(carry, j):
         best, arg, got = carry
         w = jax.lax.dynamic_slice_in_dim(head, j * vb, vb, axis=1)
-        logits = _ein("td,dv->tv", h, w.astype(jnp.float32), fp8, (-1, 0))
+        logits = ein("td,dv->tv", h, w.astype(jnp.float32), fp8, (-1, 0))
         top = jnp.max(logits, -1)
         local = read - j * vb
         inside = (local >= 0) & (local < vb)
@@ -152,19 +117,36 @@ def _head(final_scale, head, h, read, *, cfg, fp8):
     return out
 
 
-class Reference:
-    """One forward pass of ``cfg`` over a token sequence, layer by layer."""
+def layer_stacks(params, cfg):
+    """Each layer in order as (stacked weights, index, block kind), where
+    the program keeps it: pattern position p of cycle c at
+    ``layers_scan.pos<p>``, index c; the layers after the last whole cycle
+    in ``layers_tail``, given a layer axis of one here."""
+    pattern = cfg.block_pattern
+    n = len(pattern)
+    scanned = cfg.num_layers // n * n
+    for layer in range(cfg.num_layers):
+        kind = pattern[layer % n]
+        if layer < scanned:
+            yield (params["layers_scan"][f"pos{layer % n}"], layer // n,
+                   kind)
+        else:
+            tail = params["layers_tail"][layer - scanned]
+            yield jax.tree.map(lambda a: a[None], tail), 0, kind
 
-    def __init__(self, params, cfg, mode: str = "f32"):
+
+class Reference:
+    """One forward pass of ``cfg`` over a token sequence, layer by layer;
+    ``arch`` is the configuration's architecture module."""
+
+    def __init__(self, params, cfg, arch, mode: str = "f32"):
         if mode not in ("f32", "fp8"):
             raise ValueError(mode)
-        if cfg.rope != "half":
-            raise ValueError(f"the reference has half rotary only, not "
-                             f"{cfg.rope!r}")
         fp8 = mode == "fp8"
         self.params, self.cfg = params, cfg
         self.device = params["embed"].devices().pop()
-        self._layer = jax.jit(partial(_layer, cfg=cfg, fp8=fp8))
+        self._layer = jax.jit(partial(arch.layer, cfg=cfg, fp8=fp8),
+                              static_argnames="kind")
         self._head = jax.jit(partial(_head, cfg=cfg, fp8=fp8))
 
     def hidden(self, tokens) -> jax.Array:
@@ -175,9 +157,8 @@ class Reference:
         toks[:T] = tokens
         toks = jax.device_put(toks, self.device)
         x = jnp.take(self.params["embed"], toks, axis=0).astype(jnp.float32)
-        stack = self.params["layers_scan"]["pos0"]
-        for layer in range(self.cfg.num_layers):
-            x = self._layer(stack, layer, x)
+        for stack, i, kind in layer_stacks(self.params, self.cfg):
+            x = self._layer(stack, i, x, kind=kind)
         return x
 
     def head(self, h, rows, read):

@@ -48,6 +48,11 @@ class Records:
     prefills: list = dataclasses.field(default_factory=list)
     #                             prompt lengths of each traced prefill call
 
+    @property
+    def arch(self):
+        """The configuration's architecture module: its step counts."""
+        return self.cell.arch
+
 
 def percentile(xs, q: float) -> Optional[float]:
     return float(np.percentile(np.asarray(xs, float), q)) if len(xs) else None
@@ -107,15 +112,15 @@ def pick_sample(finished: list, seed: int, target_tokens: int,
     return sample
 
 
-def check(params, cfg, sample: list, control: bool = False):
+def check(params, cfg, arch, sample: list, control: bool = False):
     """Widest gap, over every served token of the sample, between the
     reference's best logit and the served token's; with ``control`` also
     the widest gap of the tokens the float8 control puts first at the same
     positions, the control's tokens in place of the served ones."""
     import reference
 
-    ref = reference.Reference(params, cfg)
-    ctl = reference.Reference(params, cfg, "fp8") if control else None
+    ref = reference.Reference(params, cfg, arch)
+    ctl = reference.Reference(params, cfg, arch, "fp8") if control else None
     widest = widest_ctl = 0.0
     for r in sample:
         g, c = reference.served_gaps(ref, r.prompt_tokens, r.generated, ctl)
@@ -180,7 +185,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
         theirs = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
                               eng.params)
         eng.params = None           # the program's own draw is not served
-        ours = weights.make(cfg, seed, dtype, eng.device)
+        ours = weights.make(cell.arch, cfg, seed, dtype, eng.device)
         weights.check_layout(ours, theirs)
         eng.params = ours
         params.append(ours)
@@ -263,7 +268,8 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     if verify:
         sample = pick_sample(finished, seed, cp["check"]["sample_tokens"],
                              cp["check"]["most_requests"])
-        widest, widest_ctl = check(params[0], cfg, sample, control)
+        widest, widest_ctl = check(params[0], cfg, cell.arch, sample,
+                                   control)
         n_tok = sum(len(r.generated) for r in sample)
         checks, correct = judge(widest, n_tok, cp["check"])
         extra.update(widest=widest, sample=[(r.rid, r.prompt_len,

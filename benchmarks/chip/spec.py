@@ -3,14 +3,20 @@
 A cell (one entry of ``workloads``) names a configuration and a traffic
 mix; the harness reads
 
-    configs/<config>.json   the model as it is run, its source and cuts
+    configs/<config>.json   the model as it is run (every ``ModelConfig``
+                            field it sets), its architecture, source, cuts
+    archs/<architecture>.py what differs between architectures: the
+                            weight tree and scales, the reference of one
+                            layer, the step counts, the CPU tests' widths
     mixes/<traffic>.json    lengths, prompt-length ladder, arrivals, SLO
     cells/<cell>.json       rate, instances and engine geometry
     metrics/<metric>.py     one reader per per-layer metric
     arrivals/<kind>.py      one arrival process per kind a mix names
 
-so a later change adds a cell, a mix, a configuration or a metric by
-adding files and entries, without editing any file that is here.
+so a later change adds a cell, a mix, a metric, or a configuration of an
+architecture already here or a new one (its module and a configuration
+file that names it), by adding files and entries, without editing any
+file that is here.
 """
 from __future__ import annotations
 
@@ -56,6 +62,7 @@ class Cell:
     traffic: str
     chips: int
     config: dict          # configs/<config>.json
+    arch: object          # archs/<architecture>.py, as a module
     mix: dict             # mixes/<traffic>.json
     params: dict          # cells/<cell>.json
     end_to_end: tuple     # the metric entries this cell reports
@@ -90,21 +97,23 @@ def load_cell(name: str, bench: Optional[dict] = None) -> Cell:
     e2e_names = {m["name"] for m in e2e}
     layer = tuple(m for m in bench["per_layer"]
                   if m["moves"] in e2e_names and _reports(m, name))
+    config = load_json(HERE / "configs" / f"{w['config']}.json")
     return Cell(
         name=name, config_name=w["config"], traffic=w["traffic"],
-        chips=w["chips"],
-        config=load_json(HERE / "configs" / f"{w['config']}.json"),
+        chips=w["chips"], config=config,
+        arch=load_module("archs", config["architecture"]),
         mix=load_json(HERE / "mixes" / f"{w['traffic']}.json"),
         params=load_json(HERE / "cells" / f"{name}.json"),
         end_to_end=e2e, per_layer=layer)
 
 
 def model_config(config: dict, **override):
-    """The program's ``ModelConfig`` for a configuration file; ``override``
+    """The program's ``ModelConfig`` from a configuration file's ``model``
+    fields (``block_pattern`` a list of block kinds); ``override``
     replaces fields (the CPU tests shrink widths this way)."""
-    from repro.configs.base import ATTN, ModelConfig
+    from repro.configs.base import ModelConfig
 
     fields = dict(config["model"], **override)
-    return ModelConfig(name=fields.pop("name", "bench"), family="dense",
-                       citation=config["paper"], block_pattern=(ATTN,),
-                       **fields)
+    fields["block_pattern"] = tuple(fields["block_pattern"])
+    return ModelConfig(name=fields.pop("name", "bench"),
+                       citation=config["paper"], **fields)

@@ -14,14 +14,31 @@ for p in (SRC, CHIP):
 
 import spec  # noqa: E402
 
-TINY = dict(num_layers=2, d_model=128, num_heads=4, num_kv_heads=2,
-            head_dim=32, d_ff=256, vocab_size=512)
 SEED = 2**31 + 7
 
 
 def load(name):
     """A cell by its name in BENCHMARK.json."""
     return spec.load_cell(name)
+
+
+def tiny_config(cell, **override):
+    """The cell's ``ModelConfig`` at its architecture's small widths."""
+    return spec.model_config(cell.config, **dict(cell.arch.TINY, **override))
+
+
+def draw(cell, cfg, seed, dtype):
+    """The benchmark's weights for ``cfg`` on the default device."""
+    import jax
+    import weights
+
+    return weights.make(cell.arch, cfg, seed, dtype, jax.devices()[0])
+
+
+def reference(cell, params, cfg, mode="f32"):
+    import reference as ref
+
+    return ref.Reference(params, cfg, cell.arch, mode)
 
 
 def tiny_cell(name="chatglm3-6b.sharegpt", limit=None, **params):
@@ -48,7 +65,7 @@ def run_tiny(cell, seed=SEED, seconds=2.0, trace=False, control=False,
                                             InstanceCostModel)
     from runner import run_cell
 
-    tiny = dict(TINY, **(override or {}))
+    tiny = dict(cell.arch.TINY, **(override or {}))
     cfg = spec.model_config(cell.config, **tiny)
     cm = InstanceCostModel(cfg=cfg, hw=HARDWARE_BY_DEVICE_KIND["TPU v5 lite"])
     keys = ("jax_compilation_cache_dir",

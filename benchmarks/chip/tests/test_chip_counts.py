@@ -1,7 +1,7 @@
 """The yardstick's arithmetic: operation and byte counts worked by hand
-for the configuration, the peaks table, and the trace reduction on a
-small trace recorded on a TPU v5e (``fixtures/small_trace.xplane.pb``,
-made by ``record_fixture.py``)."""
+for the configuration (its architecture module's counts), the peaks
+table, and the trace reduction on a small trace recorded on a TPU v5e
+(``fixtures/small_trace.xplane.pb``, made by ``record_fixture.py``)."""
 import os
 
 import pytest
@@ -17,33 +17,34 @@ FIXTURE = os.path.join(chip_tiny.CHIP, "fixtures", "small_trace.xplane.pb")
 
 
 def _cfg(name):
-    return spec.model_config(chip_tiny.load(name).config)
+    cell = chip_tiny.load(name)
+    return spec.model_config(cell.config), cell.arch
 
 
 def test_chatglm3_counts_by_hand():
-    cfg = _cfg("chatglm3-6b.sharegpt")
+    cfg, arch = _cfg("chatglm3-6b.sharegpt")
     # q 4096x4096, k and v 4096x256 each, o 4096x4096, gate/up/down
     # 3 x 4096x13696
     per_layer = (16_777_216 + 2 * 1_048_576 + 16_777_216 + 168_296_448)
-    assert counts.layer_matmul_params(cfg) == per_layer == 203_948_032
+    assert arch.layer_matmul_params(cfg) == per_layer == 203_948_032
     # + two norms (2 x 4096) + qkv bias (4096 + 2 x 256), 28 layers,
     # final norm 4096, head 4096 x 65,024; two bytes each
-    assert counts.weight_bytes(cfg) == 2 * (
+    assert arch.weight_bytes(cfg) == 2 * (
         28 * (203_948_032 + 8_192 + 4_608) + 4_096 + 266_338_304)
-    assert counts.weight_bytes(cfg) == 11_954_491_392
+    assert arch.weight_bytes(cfg) == 11_954_491_392
     # 2 (k, v) x 28 layers x 2 heads x 128 x 2 bytes = 28 KiB
-    assert counts.kv_bytes_per_token(cfg) == 28_672
+    assert arch.kv_bytes_per_token(cfg) == 28_672
     # 1,024-token prompt: 2 x 1024 x 28 x 203,948,032 matmul, causal
     # pairs 1024 x 1025 / 2 = 524,800 at 4 x 28 x 32 x 128 each, head on
     # one row 2 x 4096 x 65,024
-    assert counts.prefill_flops(cfg, 1024) == (
+    assert arch.prefill_flops(cfg, 1024) == (
         11_695_195_947_008 + 240_753_049_600 + 532_676_608)
     # decode, 3 slots with 100 + 200 + 300 context: 3 rows through the
     # layers and the head, 603 attention pairs
-    assert counts.decode_flops(cfg, 3, 600) == (
+    assert arch.decode_flops(cfg, 3, 600) == (
         2 * 3 * (28 * 203_948_032 + 266_338_304)
         + 4 * 28 * 32 * 128 * 603)
-    assert counts.decode_bytes(cfg, 3, 600) == (
+    assert arch.decode_bytes(cfg, 3, 600) == (
         11_954_491_392 + 28_672 * 603 + 2 * 3 * 4096)
 
 
@@ -94,7 +95,7 @@ def test_prefill_mfu_reads_every_program_of_the_traced_prefills():
         clock=hooks.BenchClock(), events=[], recorder=CalibrationRecorder(),
         trace=t, peak=peaks.peaks("TPU v5 lite"),
         prefills=[[1024, 512], [2048], [4096]])   # the last call untraced
-    flops = sum(counts.prefill_flops(cfg, n) for n in (1024, 512, 2048))
+    flops = sum(c.arch.prefill_flops(cfg, n) for n in (1024, 512, 2048))
     want = 100.0 * flops / 197e12 / 0.75
     assert runner.load_reader("prefill_mfu")(rec) == pytest.approx(want)
 

@@ -3,7 +3,15 @@ for each fault a served one-chip cell can have, the harness runs on the
 CPU at a small size (its look for a chip skipped) with the fault planted
 in the program's decode step, and ``correct`` must be false.  The fault
 of a missing exchange between chips has no place in these cells: their
-instances exchange nothing."""
+instances exchange nothing.
+
+Each faulty run checks every request it finished, and the half of the
+batch left out is the slots' first half, which every run fills (a request
+that finds the engine idle lands in slot 0): which slots a run fills
+beyond that, and which requests a sample holds, follow the host's
+timing, and a fault that only those would show came and went with it."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -25,7 +33,7 @@ def _faulty_steps(fault):
                 return logits, cache
             if fault == "half_batch":
                 b = logits.shape[0]
-                return logits.at[b // 2:].set(logits[:b - b // 2]), new
+                return logits.at[:b // 2].set(logits[b - b // 2:]), new
             if fault == "token_altered":
                 return jnp.roll(logits, 1, axis=-1), new
             raise ValueError(fault)
@@ -39,7 +47,10 @@ def _faulty_steps(fault):
                                    "token_altered"])
 def test_fault_makes_run_not_correct(fault, monkeypatch):
     cell = chip_tiny.tiny_cell(rate_rps=4.0)
-    limit = cell.params["check"]["widest_gap_limit"]
+    every = dict(cell.params["check"], most_requests=10**6,
+                 sample_tokens=10**9)
+    cell = dataclasses.replace(cell, params=dict(cell.params, check=every))
+    limit = every["widest_gap_limit"]
     monkeypatch.setattr(engine_mod, "serving_steps", _faulty_steps(fault))
     res = chip_tiny.run_tiny(cell)
     assert res["extra"]["finished"] > 0
